@@ -54,10 +54,8 @@ def lower_case(arch: str, shape_name: str, multi_pod: bool,
     pspecs = param_specs(spec["params"], mesh)
     pshard = to_shardings(pspecs, mesh)
     t0 = time.time()
-    # ambient mesh: activates models/hints.py. jax.set_mesh landed in
-    # jax 0.4.38; on older jax the Mesh object itself is the context
-    # manager (hints degrade to no-ops there, lowering still succeeds).
-    ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    # ambient mesh: activates models/hints.py
+    ctx = jax.set_mesh(mesh)
     ctx.__enter__()
 
     if spec["mode"] == "train":
@@ -108,8 +106,6 @@ def lower_case(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax < 0.4.38: one dict per program
-        cost = cost[0] if cost else {}
     coll = analysis.parse_collectives(compiled.as_text())
     rl = analysis.Roofline(
         flops_per_device=float(cost.get("flops", 0.0)),

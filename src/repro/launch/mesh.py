@@ -17,15 +17,6 @@ SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax.sharding.AxisType landed in jax 0.4.38; older jax's make_mesh
-    has no axis_types parameter (all axes are Auto there anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """The (data, model) single-pod or (pod, data, model) multi-pod mesh."""
     shape = MULTI_POD if multi_pod else SINGLE_POD
@@ -39,14 +30,15 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)}; "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512")
     return jax.make_mesh(
-        shape, axes, devices=devices[:n], **_axis_type_kwargs(len(axes)))
+        shape, axes, devices=devices[:n],
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """A trivial 1x1 mesh for single-device smoke runs."""
     return jax.make_mesh(
         (1, 1), ("data", "model"), devices=jax.devices()[:1],
-        **_axis_type_kwargs(2))
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple[str, ...]:

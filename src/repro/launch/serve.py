@@ -71,6 +71,7 @@ from repro.cluster import ROUTER_POLICIES, run_cluster
 from repro.cluster.faults import parse_chaos
 from repro.config import ARCH_IDS, get_config, get_smoke_config
 from repro.core.scheduler import POLICIES
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.costmodel import HardwareSpec
 from repro.serving.engine import run_policy
 from repro.serving.predictors import STRATEGIES, parse_spec
@@ -218,9 +219,13 @@ def main():
                          "requires --clients)")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--real", action="store_true",
-                    help="actually run the model (CPU-sized configs)")
+                    help="actually run the model: --smoke configs on the "
+                         "CPU; a full-size config needs a chip that holds "
+                         "its weights (granite-3-8b does not fit one "
+                         "16 GB v5e; chip_smoke.py runs a depth cut)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rate = 14.0 if args.rate is None else args.rate
@@ -458,7 +463,7 @@ def main():
         from repro.models.model import build_model
         from repro.serving.predictors import ProbePredictor
         model = build_model(cfg)
-        params = model.init(jax.random.key(args.seed))
+        params = jax.jit(model.init)(jax.random.key(args.seed))
         predictor = ProbePredictor(cfg.probe, probe_params=params["probe"],
                                    embed_table=params["embed"])
         mode = "real"

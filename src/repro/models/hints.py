@@ -20,21 +20,9 @@ from jax.sharding import PartitionSpec as P
 BATCH = "__batch__"
 
 
-def _abstract_mesh():
-    """jax.sharding.get_abstract_mesh appeared in jax 0.4.38; older jax has
-    no ambient-mesh query, so hints degrade to no-ops there."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def data_shards() -> int:
     """Number of batch-sharding ways in the ambient mesh (1 outside jit)."""
-    am = _abstract_mesh()
-    names = getattr(am, "axis_names", ())
-    if not names:
-        return 1
-    sizes = dict(zip(names, am.shape.values() if hasattr(am.shape, "values")
-                     else am.shape))
+    sizes = jax.sharding.get_abstract_mesh().shape
     n = 1
     for a in ("pod", "data"):
         n *= sizes.get(a, 1)
@@ -43,12 +31,10 @@ def data_shards() -> int:
 
 def hint(x, *spec):
     """Annotate ``x`` with a sharding hint when a mesh is active."""
-    am = _abstract_mesh()
-    names = getattr(am, "axis_names", ())
-    if not names:
+    sizes = jax.sharding.get_abstract_mesh().shape    # {} outside a mesh
+    if not sizes:
         return x
-    sizes = dict(zip(names, am.shape.values() if hasattr(am.shape, "values")
-                     else am.shape))
+    names = tuple(sizes)
     full = tuple(spec) + (None,) * (x.ndim - len(spec))
     out = []
     for dim, ax in zip(x.shape, full):

@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import functools
 import math
-import warnings
 from collections import OrderedDict
 
 import jax
@@ -53,23 +52,6 @@ from repro.config import (KIND_ATTN, KIND_HYBRID, KIND_LOCAL, KIND_MOE,
 from repro.models.ssm import ssm_dims
 
 
-def _silence_cpu_donation_warning():
-    """Silence the CPU backend's unhonored-donation warning.
-
-    Buffer donation lets XLA update the KV cache in place instead of
-    copying the whole pytree every jit call. The CPU backend (this
-    container / the CI runner) can never honor donation and warns once per
-    compiled function with identical semantics either way, so the warning
-    is pure noise there — but ONLY there: on GPU/TPU an unexpectedly
-    undonatable buffer means XLA is back to copying the cache every
-    megastep, and the warning is the signal. Install the filter lazily
-    (first donating jit / pool construction) and only on CPU.
-    """
-    if jax.default_backend() == "cpu":
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-
-
 def donating_jit(fn, donate: tuple[str, ...] = ("cache",), **jit_kwargs):
     """jit with the cache pytree donated.
 
@@ -78,7 +60,6 @@ def donating_jit(fn, donate: tuple[str, ...] = ("cache",), **jit_kwargs):
     reference to the donated argument and use the returned cache — the
     engine's single-owner ``pool.cache`` reassignment pattern.
     """
-    _silence_cpu_donation_warning()
     return jax.jit(fn, donate_argnames=donate, **jit_kwargs)
 
 
@@ -676,7 +657,6 @@ class SlotPool:
     """Host-side slot bookkeeping + device-side cache reset."""
 
     def __init__(self, model, slots: int, max_len: int):
-        _silence_cpu_donation_warning()    # covers the donating reset jits
         self.model = model
         self.cfg = model.cfg
         self.n_slots = slots
@@ -734,7 +714,6 @@ class PagedSlotPool(SlotPool):
 
     def __init__(self, model, slots: int, max_len: int, page_size: int = 16,
                  retain: bool | None = None, prefix_cache: bool = False):
-        _silence_cpu_donation_warning()    # covers the donating reset jits
         self.page_size = page_size
         self.pages_per_seq = pages_for_tokens(max_len, page_size)
         self.model = model

@@ -8,6 +8,7 @@ import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -142,6 +143,36 @@ def test_serve_cli_invalid_flags_exit_2():
         assert out.returncode == 2, (argv, out.returncode, out.stderr)
         assert "error:" in out.stderr, (argv, out.stderr)
         assert "Traceback" not in out.stderr, (argv, out.stderr)
+
+
+def test_compile_cache_goes_where_the_environment_says(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, a real-mode serve run writes its
+    compiled programs there (and the code sets no other directory)."""
+    cache = tmp_path / "jax-cache"
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "trail-llama",
+         "--smoke", "--real", "--n", "2", "--kv-layout", "paged"],
+        env={**os.environ, "PYTHONPATH": os.path.join(ROOT, "src"),
+             "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(cache),
+             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"},
+        capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert any(p.name.endswith("-cache") for p in cache.iterdir())
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
+    assert CHECKOUT_CACHE_DIR == Path(ROOT).resolve() / ".jax_cache"
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert use_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == was     # nothing set
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert use_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_oracle_predictor_statistics():
