@@ -12,6 +12,9 @@ Submodules:
   SLO-attainment curves, and preemption/swap counters.
 * ``emitters``  — shared JSON and markdown-table emitters used by every
   benchmark artifact.
+* ``spans``     — `PhaseRecorder`: profiler spans and always-on counters
+  (time, calls, longest call, programs built) for the host phases of a
+  real-mode ``Engine.step``; sim engines share the no-op `NO_PHASES`.
 """
 
 from repro.metrics.emitters import report_json, report_markdown

@@ -286,42 +286,45 @@ class Model:
         tokens: (B,C); valid: (B,C) bool (contiguous prefixes) or None.
         Returns (next_logits (B,V), cache, tap_sum (B,d), tap_cnt (B,)).
         """
-        cfg = self.cfg
-        B, C = tokens.shape
-        if valid is None:
-            valid = jnp.ones((B, C), bool)
-        offsets = cache["lengths"]
+        with jax.named_scope("prefill"):
+            cfg = self.cfg
+            B, C = tokens.shape
+            if valid is None:
+                valid = jnp.ones((B, C), bool)
+            offsets = cache["lengths"]
 
-        if enc_embeds is not None:
-            enc_out = self.encode(params, enc_embeds)
-            cache = self.build_cross_cache(params, cache, enc_out)
+            if enc_embeds is not None:
+                enc_out = self.encode(params, enc_embeds)
+                cache = self.build_cross_cache(params, cache, enc_out)
 
-        q_pos = jnp.where(valid, offsets[:, None] + jnp.arange(C, dtype=jnp.int32),
-                          -1)
-        h = self._embed(params, tokens, q_pos)
-        if prefix_embeds is not None:
-            P = prefix_embeds.shape[1]
-            ppos = offsets[:, None] + jnp.arange(P, dtype=jnp.int32)
-            h = jnp.concatenate([prefix_embeds.astype(h.dtype), h], axis=1)
-            q_pos = jnp.concatenate(
-                [ppos, jnp.where(valid, q_pos + P, -1)], axis=1)
-            valid = jnp.concatenate([jnp.ones((B, P), bool), valid], axis=1)
+            q_pos = jnp.where(valid, offsets[:, None] + jnp.arange(C, dtype=jnp.int32),
+                              -1)
+            h = self._embed(params, tokens, q_pos)
+            if prefix_embeds is not None:
+                P = prefix_embeds.shape[1]
+                ppos = offsets[:, None] + jnp.arange(P, dtype=jnp.int32)
+                h = jnp.concatenate([prefix_embeds.astype(h.dtype), h], axis=1)
+                q_pos = jnp.concatenate(
+                    [ppos, jnp.where(valid, q_pos + P, -1)], axis=1)
+                valid = jnp.concatenate([jnp.ones((B, P), bool), valid], axis=1)
 
-        h, new_cache, tap, _ = self._cached_trunk(params, cache, h, q_pos,
-                                                  decode=False)
-        n_new = jnp.sum(valid, axis=1).astype(jnp.int32)
-        new_cache["lengths"] = offsets + n_new
+            h, new_cache, tap, _ = self._cached_trunk(params, cache, h, q_pos,
+                                                      decode=False)
+            n_new = jnp.sum(valid, axis=1).astype(jnp.int32)
+            new_cache["lengths"] = offsets + n_new
 
-        # next-token logits from the last valid position of each row
-        last_idx = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0)
-        h_last = h[jnp.arange(B), last_idx]
-        h_last = apply_norm(cfg, params["final_norm"], h_last)
-        logits = unembed(cfg, params, h_last)
+            # next-token logits from the last valid position of each row
+            last_idx = jnp.maximum(jnp.sum(valid, axis=1) - 1, 0)
+            h_last = h[jnp.arange(B), last_idx]
+            with jax.named_scope("lm_head"):
+                h_last = apply_norm(cfg, params["final_norm"], h_last)
+                logits = unembed(cfg, params, h_last)
 
-        # paper: prompt-phase probe input = mean of prompt-token taps
-        vmask = valid[..., None].astype(jnp.float32)
-        tap_sum = jnp.sum(tap.astype(jnp.float32) * vmask, axis=1)
-        return logits, new_cache, tap_sum, n_new
+            # paper: prompt-phase probe input = mean of prompt-token taps
+            with jax.named_scope("probe"):
+                vmask = valid[..., None].astype(jnp.float32)
+                tap_sum = jnp.sum(tap.astype(jnp.float32) * vmask, axis=1)
+            return logits, new_cache, tap_sum, n_new
 
     def decode_step(self, params, cache, tokens, active=None):
         """One iteration: generate-one-token for every active row.
@@ -342,10 +345,12 @@ class Model:
         # dropped via position -1; SSM state needs an explicit select)
         new_cache = _mask_recurrent(cache, new_cache, active)
         new_cache["lengths"] = lengths + active.astype(jnp.int32)
-        hn = apply_norm(cfg, params["final_norm"], h[:, 0])
-        logits = unembed(cfg, params, hn)
-        tap = tap[:, 0]
-        probe_logits = predictor.apply_probe(params["probe"], tap)
+        with jax.named_scope("lm_head"):
+            hn = apply_norm(cfg, params["final_norm"], h[:, 0])
+            logits = unembed(cfg, params, hn)
+        with jax.named_scope("probe"):
+            tap = tap[:, 0]
+            probe_logits = predictor.apply_probe(params["probe"], tap)
         return logits, new_cache, tap, probe_logits
 
     def decode_multi(self, params, cache, tokens, active=None, budget=None,
@@ -382,19 +387,22 @@ class Model:
             act = active & ~halted & (emitted < budget)
             logits, cache, _, probe_logits = self.decode_step(
                 params, cache, tok, active=act)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if eos_id >= 0:
                 halted = halted | (act & (nxt == eos_id))
             emitted = emitted + act.astype(jnp.int32)
-            probs = jax.nn.softmax(probe_logits.astype(jnp.float32), axis=-1)
+            with jax.named_scope("probe"):
+                probs = jax.nn.softmax(probe_logits.astype(jnp.float32), axis=-1)
             tok_out = jnp.where(act, nxt, -1)
             tok_next = jnp.where(act, nxt, tok[:, 0])[:, None]
             return (cache, tok_next, emitted, halted), (tok_out, probs)
 
-        carry0 = (cache, tokens, jnp.zeros((B,), jnp.int32),
-                  jnp.zeros((B,), bool))
-        (cache, _, emitted, _), (toks, probs) = jax.lax.scan(
-            step, carry0, None, length=k)
+        with jax.named_scope("decode"):
+            carry0 = (cache, tokens, jnp.zeros((B,), jnp.int32),
+                      jnp.zeros((B,), bool))
+            (cache, _, emitted, _), (toks, probs) = jax.lax.scan(
+                step, carry0, None, length=k)
         return (jnp.moveaxis(toks, 0, 1), cache,
                 jnp.moveaxis(probs, 0, 1), emitted)
 

@@ -176,16 +176,17 @@ def block_cached(cfg: ModelConfig, kind: str, p, h, cache_l, q_pos,
         return h + y, new_cache, jnp.float32(0)
 
     window = _kind_window(cfg, kind)
-    if "pk" in cache_l:
-        kvcache = {k: cache_l[k] for k in ("pk", "pv", "pkpos")}
-        a, kv_new = attn.self_attention_paged(cfg, p["attn"], hn, kvcache,
-                                              q_pos, block_table,
-                                              use_kernels=use_kernels)
-    else:
-        kv_keys = ("k", "v", "kpos", "k_scale", "v_scale")
-        kvcache = {k: cache_l[k] for k in kv_keys if k in cache_l}
-        a, kv_new = attn.self_attention_cached(cfg, p["attn"], hn, kvcache,
-                                               q_pos, window=window)
+    with jax.named_scope("attention"):
+        if "pk" in cache_l:
+            kvcache = {k: cache_l[k] for k in ("pk", "pv", "pkpos")}
+            a, kv_new = attn.self_attention_paged(cfg, p["attn"], hn, kvcache,
+                                                  q_pos, block_table,
+                                                  use_kernels=use_kernels)
+        else:
+            kv_keys = ("k", "v", "kpos", "k_scale", "v_scale")
+            kvcache = {k: cache_l[k] for k in kv_keys if k in cache_l}
+            a, kv_new = attn.self_attention_cached(cfg, p["attn"], hn, kvcache,
+                                                   q_pos, window=window)
     new_cache.update(kv_new)
     if kind == KIND_HYBRID:
         if decode:
@@ -202,7 +203,8 @@ def block_cached(cfg: ModelConfig, kind: str, p, h, cache_l, q_pos,
         h = h + attn.cross_attention(cfg, p["cross"],
                                      apply_norm(cfg, p["norm_cross"], h),
                                      cache_l["ck"], cache_l["cv"])
-    y, aux = _mlp_part(cfg, kind, p, h)
+    with jax.named_scope("mlp"):
+        y, aux = _mlp_part(cfg, kind, p, h)
     return h + y, new_cache, aux
 
 

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.config import ModelConfig
 from repro.core.scheduler import Decision, ReqState, SchedEntry, select_batch
+from repro.metrics.spans import NO_PHASES, PhaseRecorder
 from repro.serving.costmodel import CostModel, HardwareSpec
 from repro.serving.kv_cache import (BlockManager, PagedSlotPool, SlotPool,
                                     bytes_for_context, donating_jit,
@@ -351,6 +352,9 @@ class Engine:
         self.cfg = cfg
         self.ecfg = ecfg
         self.events = event_log
+        # host phases of step() (repro.metrics.spans): timed in real mode,
+        # the shared no-op in sim mode; observation only either way
+        self.phases = PhaseRecorder() if ecfg.mode == "real" else NO_PHASES
         if predictor is not None:
             self.predictor = predictor
         elif ecfg.predictor:
@@ -731,7 +735,17 @@ class Engine:
         chunk + one decode megastep, and advances the clock. If no request
         is live the clock jumps to the next pending arrival (an idle step,
         ``ran=False``); a drained engine returns immediately.
+
+        The iteration is timed as the host phase ``engine.step`` of
+        ``self.phases``, its parts as the phases ``engine.admit``,
+        ``engine.schedule``, ``engine.apply``, ``engine.pages``, those of
+        `_device_step` (real mode) and ``engine.book``.
         """
+        with self.phases("engine.step"):
+            return self._step()
+
+    def _step(self) -> StepResult:
+        ph = self.phases
         ecfg = self.ecfg
         stats = self.stats
         pool_reqs = self._pool_reqs
@@ -740,29 +754,30 @@ class Engine:
         ev = self.events
         ev_mark = len(ev) if ev is not None else 0
 
-        self._admit_arrivals(now)
-        # resilience checks run at megastep boundaries, before the
-        # scheduler sees the pool; both are gated so the default engine
-        # (no deadlines, no watermark) takes neither branch
-        if self._deadlines:
-            self._expire_deadlines(now)
-        if ecfg.shed_watermark > 0.0:
-            self._shed_overload()
-        if ecfg.prefill_only:
-            # disaggregated-prefill role: a request whose prefill is
-            # complete parks for KV handoff instead of decoding. Parking
-            # is not a preemption (no stats/events) — the request simply
-            # leaves the schedulable set with its pages retained, where
-            # it stays evictable under memory pressure until the router
-            # exports it.
-            for r in pool_reqs.values():
-                if (not r.done and r.rid not in self._parked
-                        and r.entry.prefill_done >= r.context_len - 1):
-                    if r.entry.state is ReqState.RUNNING:
-                        self._suspend(r)
-                    self._parked.add(r.rid)
-        live = [r for r in pool_reqs.values()
-                if not r.done and r.rid not in self._parked]
+        with ph("engine.admit"):
+            self._admit_arrivals(now)
+            # resilience checks run at megastep boundaries, before the
+            # scheduler sees the pool; both are gated so the default engine
+            # (no deadlines, no watermark) takes neither branch
+            if self._deadlines:
+                self._expire_deadlines(now)
+            if ecfg.shed_watermark > 0.0:
+                self._shed_overload()
+            if ecfg.prefill_only:
+                # disaggregated-prefill role: a request whose prefill is
+                # complete parks for KV handoff instead of decoding. Parking
+                # is not a preemption (no stats/events) — the request simply
+                # leaves the schedulable set with its pages retained, where
+                # it stays evictable under memory pressure until the router
+                # exports it.
+                for r in pool_reqs.values():
+                    if (not r.done and r.rid not in self._parked
+                            and r.entry.prefill_done >= r.context_len - 1):
+                        if r.entry.state is ReqState.RUNNING:
+                            self._suspend(r)
+                        self._parked.add(r.rid)
+            live = [r for r in pool_reqs.values()
+                    if not r.done and r.rid not in self._parked]
         if not live:
             if self._p_idx < len(self._pending):
                 # idle: jump to next arrival
@@ -786,21 +801,23 @@ class Engine:
         if self._parked:
             sched_entries = {rid: e for rid, e in entries.items()
                              if rid not in self._parked}
-        decision = select_batch(
-            sched_entries, policy=ecfg.policy, max_batch=ecfg.max_batch,
-            mem_budget=ecfg.mem_budget,
-            bytes_fn=lambda e: self._bytes_for(
-                pool_reqs[e.rid].context_len + self._k),
-            lookahead=self._k, now=now, age_boost=ecfg.age_boost,
-            age_delay=ecfg.age_delay_s,
-            deadline_slack=ecfg.deadline_slack_s)
+        with ph("engine.schedule"):
+            decision = select_batch(
+                sched_entries, policy=ecfg.policy, max_batch=ecfg.max_batch,
+                mem_budget=ecfg.mem_budget,
+                bytes_fn=lambda e: self._bytes_for(
+                    pool_reqs[e.rid].context_len + self._k),
+                lookahead=self._k, now=now, age_boost=ecfg.age_boost,
+                age_delay=ecfg.age_delay_s,
+                deadline_slack=ecfg.deadline_slack_s)
 
-        self._apply_preemptions(decision, pool_reqs, stats)
-        if self.paged:
-            # page-granular memory pressure: suspended (preempted but
-            # resident) pages yield before any admitted request starts
-            self._reclaim_pages(decision, pool_reqs, entries, stats)
-        self._apply_admissions(decision, pool_reqs, stats)
+        with ph("engine.apply"):
+            self._apply_preemptions(decision, pool_reqs, stats)
+            if self.paged:
+                # page-granular memory pressure: suspended (preempted but
+                # resident) pages yield before any admitted request starts
+                self._reclaim_pages(decision, pool_reqs, entries, stats)
+            self._apply_admissions(decision, pool_reqs, stats)
 
         # Prefill covers context_len - 1 tokens; the final known token is
         # always consumed by decode_step (which emits the next one). This
@@ -833,26 +850,27 @@ class Engine:
             pf_plan.append((r, take))
             budget -= take
 
-        if self.paged:
-            # allocate pages ahead of the writes this iteration performs
-            # (decode rows pre-reserve their whole megastep budget: the
-            # block table is frozen while the k steps run on device)
-            for r, take in pf_plan:
-                self._ensure_pages(r, r.entry.prefill_done + take, entries)
-            for r in decoding:
-                self._ensure_pages(
-                    r, r.context_len + self._row_budget(r) - 1, entries)
-        if self.prefix_cache:
-            # COW guard: any shared page covering a position about to be
-            # written is replaced by a private copy first (a no-op in the
-            # standard flow — shared pages are full and writes land past
-            # them — but it makes the immutability invariant enforced)
-            make_writable = (self.pool.make_writable if self.pool is not None
-                             else self.blocks.make_writable)
-            for r, _take in pf_plan:
-                make_writable(r.rid, r.entry.prefill_done)
-            for r in decoding:
-                make_writable(r.rid, max(r.context_len - 1, 0))
+        with ph("engine.pages"):
+            if self.paged:
+                # allocate pages ahead of the writes this iteration performs
+                # (decode rows pre-reserve their whole megastep budget: the
+                # block table is frozen while the k steps run on device)
+                for r, take in pf_plan:
+                    self._ensure_pages(r, r.entry.prefill_done + take, entries)
+                for r in decoding:
+                    self._ensure_pages(
+                        r, r.context_len + self._row_budget(r) - 1, entries)
+            if self.prefix_cache:
+                # COW guard: any shared page covering a position about to be
+                # written is replaced by a private copy first (a no-op in the
+                # standard flow — shared pages are full and writes land past
+                # them — but it makes the immutability invariant enforced)
+                make_writable = (self.pool.make_writable if self.pool is not None
+                                 else self.blocks.make_writable)
+                for r, _take in pf_plan:
+                    make_writable(r.rid, r.entry.prefill_done)
+                for r in decoding:
+                    make_writable(r.rid, max(r.context_len - 1, 0))
 
         # capture per-row decode contexts before tokens are appended:
         # the cost model charges context c+1..c+n for a row emitting n
@@ -862,134 +880,135 @@ class Engine:
         else:
             emitted = self._sim_step(pf_plan, decoding)
 
-        # ---- bookkeeping / clock -------------------------------------
-        pf_tokens = sum(t for _, t in pf_plan)
-        pf_ctx = max((r.context_len for r, _ in pf_plan), default=0)
-        dt = self.cost.megastep_time(
-            dec_ctxs, [emitted.get(r.rid, 0) for r in decoding],
-            pf_tokens, pf_ctx)
-        dt += self._swap_pending_s              # DMA stalls the batch
-        self._swap_pending_s = 0.0
-        # externally-priced predictor work (BERT-sized prompt models,
-        # ELIS proxy re-predictions) charged this step stalls the clock;
-        # zero-flop strategies (recycled probe, analysis oracles) add
-        # exactly 0.0 — legacy results stay byte-identical
-        pred_flops = self.predictor.take_cost_flops()
-        if pred_flops:
-            pred_s = self.cost.predictor_time(pred_flops)
-            dt += pred_s
-            stats.predictor_time_s += pred_s
-        stats.predictor_calls = self.predictor.cost_calls
-        if self._slowdown != 1.0:
-            # straggler fault injection: the replica's hardware runs
-            # slower, dilating the whole megastep (compute, DMA stalls,
-            # predictor work). 1.0 — the default — leaves the clock
-            # byte-identical to the pre-resilience engine.
-            dt *= self._slowdown
-        now_next = now + dt
-        completed: list[Request] = []
-        for r, take in pf_plan:
-            r.entry.prefill_done += take
-            # tokens actually materialized in the cache (never credited
-            # past what was written: a mid-prefill preemption must not
-            # mark unwritten positions as retained). The prefilled_tokens
-            # stat counts only the newly materialized portion: a decoded
-            # row re-enters the prefill classification to catch
-            # prefill_done up to its grown context, but those positions
-            # were already KV-written by decode and are not fresh prefill
-            # work.
-            kv_before = getattr(r, "_kv_written", 0)
-            stats.prefilled_tokens += max(r.entry.prefill_done - kv_before, 0)
-            r._kv_written = max(kv_before, r.entry.prefill_done)
-        for r in decoding:
-            n = emitted.get(r.rid, 0)
-            r._kv_written = max(getattr(r, "_kv_written", 0),
-                                r.context_len - 1)
-            r.entry.age += n
-            if r.first_token_time < 0 and n > 0:
-                r.first_token_time = now_next
-                if ev is not None:
-                    ev.emit(now_next, r.rid, "first_token")
-                if self._subs:
-                    self._notify(now_next, r.rid, "first_token")
-            if ev is not None and n > 0:
-                ev.emit(now_next, r.rid, "tokens", n)
-            if self._subs and n > 0:
-                self._notify(now_next, r.rid, "tokens", float(n))
-            if (len(r.generated) >= r.true_out_len
-                    or len(r.generated) >= r.max_new_tokens):
-                r.entry.state = ReqState.FINISHED
-                r.finish_time = now_next
-                stats.latencies.append(r.latency())
-                stats.ttfts.append(r.ttft())
-                completed.append(r)
-                if ev is not None:
-                    ev.emit(now_next, r.rid, "finish")
-                if self._subs:
-                    self._notify(now_next, r.rid, "finish")
-                if self.prefix_cache:
-                    # publish the finished request's prompt pages before
-                    # release parks them in the reusable pool
-                    self._register_prompt(r)
-                if self.pool is not None:
-                    self.pool.release(r.rid)
-                elif r.slot >= 0:
-                    r.slot = -1
-                if self.blocks is not None and self.pool is None:
-                    # sim mode only: real-mode release() freed the pages
-                    self.blocks.free_request(r.rid)
-
-        if self.blocks is not None:
-            for rid in decision.scheduled:
-                r = pool_reqs[rid]
-                if not r.done:
-                    self.blocks.note_cached(
-                        rid, getattr(r, "_kv_written", 0))
+        # ---- bookkeeping / clock -----------------------------------------
+        with ph("engine.book"):
+            pf_tokens = sum(t for _, t in pf_plan)
+            pf_ctx = max((r.context_len for r, _ in pf_plan), default=0)
+            dt = self.cost.megastep_time(
+                dec_ctxs, [emitted.get(r.rid, 0) for r in decoding],
+                pf_tokens, pf_ctx)
+            dt += self._swap_pending_s              # DMA stalls the batch
+            self._swap_pending_s = 0.0
+            # externally-priced predictor work (BERT-sized prompt models,
+            # ELIS proxy re-predictions) charged this step stalls the clock;
+            # zero-flop strategies (recycled probe, analysis oracles) add
+            # exactly 0.0 — legacy results stay byte-identical
+            pred_flops = self.predictor.take_cost_flops()
+            if pred_flops:
+                pred_s = self.cost.predictor_time(pred_flops)
+                dt += pred_s
+                stats.predictor_time_s += pred_s
+            stats.predictor_calls = self.predictor.cost_calls
+            if self._slowdown != 1.0:
+                # straggler fault injection: the replica's hardware runs
+                # slower, dilating the whole megastep (compute, DMA stalls,
+                # predictor work). 1.0 — the default — leaves the clock
+                # byte-identical to the pre-resilience engine.
+                dt *= self._slowdown
+            now_next = now + dt
+            completed: list[Request] = []
+            for r, take in pf_plan:
+                r.entry.prefill_done += take
+                # tokens actually materialized in the cache (never credited
+                # past what was written: a mid-prefill preemption must not
+                # mark unwritten positions as retained). The prefilled_tokens
+                # stat counts only the newly materialized portion: a decoded
+                # row re-enters the prefill classification to catch
+                # prefill_done up to its grown context, but those positions
+                # were already KV-written by decode and are not fresh prefill
+                # work.
+                kv_before = getattr(r, "_kv_written", 0)
+                stats.prefilled_tokens += max(r.entry.prefill_done - kv_before, 0)
+                r._kv_written = max(kv_before, r.entry.prefill_done)
+            for r in decoding:
+                n = emitted.get(r.rid, 0)
+                r._kv_written = max(getattr(r, "_kv_written", 0),
+                                    r.context_len - 1)
+                r.entry.age += n
+                if r.first_token_time < 0 and n > 0:
+                    r.first_token_time = now_next
+                    if ev is not None:
+                        ev.emit(now_next, r.rid, "first_token")
+                    if self._subs:
+                        self._notify(now_next, r.rid, "first_token")
+                if ev is not None and n > 0:
+                    ev.emit(now_next, r.rid, "tokens", n)
+                if self._subs and n > 0:
+                    self._notify(now_next, r.rid, "tokens", float(n))
+                if (len(r.generated) >= r.true_out_len
+                        or len(r.generated) >= r.max_new_tokens):
+                    r.entry.state = ReqState.FINISHED
+                    r.finish_time = now_next
+                    stats.latencies.append(r.latency())
+                    stats.ttfts.append(r.ttft())
+                    completed.append(r)
+                    if ev is not None:
+                        ev.emit(now_next, r.rid, "finish")
+                    if self._subs:
+                        self._notify(now_next, r.rid, "finish")
                     if self.prefix_cache:
+                        # publish the finished request's prompt pages before
+                        # release parks them in the reusable pool
                         self._register_prompt(r)
-        if self.prefix_cache:
-            gen = self.blocks.index_gen
-            for r in pool_reqs.values():
-                if r.done:
-                    continue
-                if r.entry.state is ReqState.WAITING:
-                    # refresh the prospective hit only when the index
-                    # actually changed (generation-gated): match_prefix
-                    # is O(prompt pages) and a long WAITING queue would
-                    # otherwise pay it every step for nothing
-                    if self._hint_gen.get(r.rid) != gen:
-                        self._prefix_hint[r.rid] = self.blocks.match_len(
-                            self._match_tokens(r))
-                        self._hint_gen[r.rid] = gen
-                    self._sync_prefill_left(
-                        r, self._prefix_hint.get(r.rid, 0))
-                else:
-                    self._sync_prefill_left(r)
+                    if self.pool is not None:
+                        self.pool.release(r.rid)
+                    elif r.slot >= 0:
+                        r.slot = -1
+                    if self.blocks is not None and self.pool is None:
+                        # sim mode only: real-mode release() freed the pages
+                        self.blocks.free_request(r.rid)
 
-        if self.prefix_cache:
-            # page-accurate under sharing: each physical page counts once
-            # however many block tables reference it, and reusable cache
-            # pages (refcount zero) are reclaimable, hence free
-            mem = self.cost.resident_page_bytes(self.blocks.used_pages())
-        else:
-            mem = sum(self._bytes_for(pool_reqs[rid].context_len)
-                      for rid in decision.scheduled)
             if self.blocks is not None:
-                mem += self._page_bytes * sum(
-                    self.blocks.resident_pages(e.rid)
-                    for e in entries.values()
-                    if e.state is ReqState.PREEMPTED)
-        stats.peak_mem_bytes = max(stats.peak_mem_bytes, mem)
-        self._last_mem = mem
-        stats.iterations += 1
-        self._now = now_next
-        stats.sim_time = (self._now if ecfg.mode == "sim"
-                          else time.perf_counter() - self._wall0)
-        return StepResult(completed=completed, now=self._now,
-                          backlog_fn=self.backlog, ran=True,
-                          kv_headroom=self.kv_headroom(),
-                          events=ev.events[ev_mark:] if ev is not None
-                          else ())
+                for rid in decision.scheduled:
+                    r = pool_reqs[rid]
+                    if not r.done:
+                        self.blocks.note_cached(
+                            rid, getattr(r, "_kv_written", 0))
+                        if self.prefix_cache:
+                            self._register_prompt(r)
+            if self.prefix_cache:
+                gen = self.blocks.index_gen
+                for r in pool_reqs.values():
+                    if r.done:
+                        continue
+                    if r.entry.state is ReqState.WAITING:
+                        # refresh the prospective hit only when the index
+                        # actually changed (generation-gated): match_prefix
+                        # is O(prompt pages) and a long WAITING queue would
+                        # otherwise pay it every step for nothing
+                        if self._hint_gen.get(r.rid) != gen:
+                            self._prefix_hint[r.rid] = self.blocks.match_len(
+                                self._match_tokens(r))
+                            self._hint_gen[r.rid] = gen
+                        self._sync_prefill_left(
+                            r, self._prefix_hint.get(r.rid, 0))
+                    else:
+                        self._sync_prefill_left(r)
+
+            if self.prefix_cache:
+                # page-accurate under sharing: each physical page counts once
+                # however many block tables reference it, and reusable cache
+                # pages (refcount zero) are reclaimable, hence free
+                mem = self.cost.resident_page_bytes(self.blocks.used_pages())
+            else:
+                mem = sum(self._bytes_for(pool_reqs[rid].context_len)
+                          for rid in decision.scheduled)
+                if self.blocks is not None:
+                    mem += self._page_bytes * sum(
+                        self.blocks.resident_pages(e.rid)
+                        for e in entries.values()
+                        if e.state is ReqState.PREEMPTED)
+            stats.peak_mem_bytes = max(stats.peak_mem_bytes, mem)
+            self._last_mem = mem
+            stats.iterations += 1
+            self._now = now_next
+            stats.sim_time = (self._now if ecfg.mode == "sim"
+                              else time.perf_counter() - self._wall0)
+            return StepResult(completed=completed, now=self._now,
+                              backlog_fn=self.backlog, ran=True,
+                              kv_headroom=self.kv_headroom(),
+                              events=ev.events[ev_mark:] if ev is not None
+                              else ())
 
     # ------------------------------------------------------------------
     def run(self, requests: list[Request]) -> EngineStats:
@@ -1625,11 +1644,18 @@ class Engine:
         bookkeeping while the k-step decode megastep is still executing.
         The only decode-side host transfer is O(B*k) token ids plus
         O(B*k*num_bins) probe posteriors — never the (B, vocab) logits.
+
+        Host phases: ``engine.flush`` (pending resets, COW copies, block
+        table), ``engine.dispatch`` (staging and the calls, up to enqueue),
+        ``engine.fetch`` (each wait for device outputs) and
+        ``engine.tokens`` (the predictor's per-prompt and per-token calls).
         """
         import jax.numpy as jnp
+        ph = self.phases
         pool = self.pool
         B = pool.n_slots
-        pool.flush_resets()
+        with ph("engine.flush"):
+            pool.flush_resets()
         pf_out = None
         # The scheduler's prefill classification runs on prefill_done, which
         # trails _kv_written after a decode megastep (decode writes KV for
@@ -1640,74 +1666,79 @@ class Engine:
         # covers only the genuinely unwritten slice of each chunk, keeping
         # lengths == _kv_written at every megastep boundary (the invariant
         # page export/import relies on).
-        feed: list[tuple[Request, int, int]] = []
-        for r, take in pf_plan:
-            done = r.entry.prefill_done
-            skip = min(max(getattr(r, "_kv_written", 0) - done, 0), take)
-            if take > skip:
-                feed.append((r, done + skip, take - skip))
-        if feed:
-            # bucketize the chunk width (powers of two) to bound recompiles
-            need = max(n for _, _, n in feed)
-            chunk = 8
-            while chunk < need:
-                chunk *= 2
-            chunk = min(chunk, self.ecfg.prefill_chunk)
-            tokens = np.zeros((B, chunk), np.int32)
-            valid = np.zeros((B, chunk), bool)
-            for r, start, n in feed:
-                full = r.prompt + r.generated
-                seg = full[start:start + n]
-                tokens[r.slot, :len(seg)] = seg
-                valid[r.slot, :len(seg)] = True
-            _, pool.cache, tap_sum, n_new = self._prefill_fn(
-                self.params, pool.cache, jnp.asarray(tokens),
-                valid=jnp.asarray(valid))
-            pf_out = (tap_sum, n_new)
-        dec_out = None
-        if decoding:
-            tokens = np.zeros((B, 1), np.int32)
-            active = np.zeros((B,), bool)
-            budget = np.zeros((B,), np.int32)
-            for r in decoding:
-                tokens[r.slot, 0] = (r.generated[-1] if r.generated
-                                     else (r.prompt[-1] if r.prompt else 1))
-                active[r.slot] = True
-                budget[r.slot] = self._row_budget(r)
-            toks, pool.cache, probs, n_emit = self._decode_fn(
-                self.params, pool.cache, jnp.asarray(tokens),
-                jnp.asarray(active), jnp.asarray(budget), k=self._k)
-            dec_out = (toks, probs, n_emit)
+        with ph("engine.dispatch"):
+            feed: list[tuple[Request, int, int]] = []
+            for r, take in pf_plan:
+                done = r.entry.prefill_done
+                skip = min(max(getattr(r, "_kv_written", 0) - done, 0), take)
+                if take > skip:
+                    feed.append((r, done + skip, take - skip))
+            if feed:
+                # bucketize the chunk width (powers of two) to bound recompiles
+                need = max(n for _, _, n in feed)
+                chunk = 8
+                while chunk < need:
+                    chunk *= 2
+                chunk = min(chunk, self.ecfg.prefill_chunk)
+                tokens = np.zeros((B, chunk), np.int32)
+                valid = np.zeros((B, chunk), bool)
+                for r, start, n in feed:
+                    full = r.prompt + r.generated
+                    seg = full[start:start + n]
+                    tokens[r.slot, :len(seg)] = seg
+                    valid[r.slot, :len(seg)] = True
+                _, pool.cache, tap_sum, n_new = self._prefill_fn(
+                    self.params, pool.cache, jnp.asarray(tokens),
+                    valid=jnp.asarray(valid))
+                pf_out = (tap_sum, n_new)
+            dec_out = None
+            if decoding:
+                tokens = np.zeros((B, 1), np.int32)
+                active = np.zeros((B,), bool)
+                budget = np.zeros((B,), np.int32)
+                for r in decoding:
+                    tokens[r.slot, 0] = (r.generated[-1] if r.generated
+                                         else (r.prompt[-1] if r.prompt else 1))
+                    active[r.slot] = True
+                    budget[r.slot] = self._row_budget(r)
+                toks, pool.cache, probs, n_emit = self._decode_fn(
+                    self.params, pool.cache, jnp.asarray(tokens),
+                    jnp.asarray(active), jnp.asarray(budget), k=self._k)
+                dec_out = (toks, probs, n_emit)
 
         if pf_out is not None:
-            tap_sum = np.asarray(pf_out[0])
-            n_new = np.asarray(pf_out[1])
-            for r, start, n in feed:
-                if r.tap_sum is None:
-                    r.tap_sum = np.zeros(self.cfg.d_model, np.float32)
-                r.tap_sum = r.tap_sum + tap_sum[r.slot]
-                r.tap_cnt += int(n_new[r.slot])
-                if start + n >= r.context_len - 1:
-                    tap_mean = r.tap_sum / max(r.tap_cnt, 1)
-                    pred = self.predictor.on_prefill(r, tap_mean)
-                    r.entry.pred_remaining = pred
+            with ph("engine.fetch"):
+                tap_sum = np.asarray(pf_out[0])
+                n_new = np.asarray(pf_out[1])
+            with ph("engine.tokens"):
+                for r, start, n in feed:
+                    if r.tap_sum is None:
+                        r.tap_sum = np.zeros(self.cfg.d_model, np.float32)
+                    r.tap_sum = r.tap_sum + tap_sum[r.slot]
+                    r.tap_cnt += int(n_new[r.slot])
+                    if start + n >= r.context_len - 1:
+                        tap_mean = r.tap_sum / max(r.tap_cnt, 1)
+                        pred = self.predictor.on_prefill(r, tap_mean)
+                        r.entry.pred_remaining = pred
         emitted: dict[int, int] = {}
         if dec_out is not None:
-            toks_np = np.asarray(dec_out[0])
-            probs_np = np.asarray(dec_out[1])
-            n_np = np.asarray(dec_out[2])
-            for r in decoding:
-                n = int(n_np[r.slot])
-                for t in range(n):
-                    r.generated.append(int(toks_np[r.slot, t]))
-                    if len(r.generated) % self.ecfg.probe_interval == 0:
-                        # device-side softmax posterior at the probe boundary
-                        r.entry.pred_remaining = self.predictor.on_token(
-                            r, probs_np[r.slot, t])
-                    else:   # between probes: deterministic aging
-                        r.entry.pred_remaining = max(
-                            r.entry.pred_remaining - 1.0, 0.0)
-                emitted[r.rid] = n
+            with ph("engine.fetch"):
+                toks_np = np.asarray(dec_out[0])
+                probs_np = np.asarray(dec_out[1])
+                n_np = np.asarray(dec_out[2])
+            with ph("engine.tokens"):
+                for r in decoding:
+                    n = int(n_np[r.slot])
+                    for t in range(n):
+                        r.generated.append(int(toks_np[r.slot, t]))
+                        if len(r.generated) % self.ecfg.probe_interval == 0:
+                            # device-side softmax posterior at the probe boundary
+                            r.entry.pred_remaining = self.predictor.on_token(
+                                r, probs_np[r.slot, t])
+                        else:   # between probes: deterministic aging
+                            r.entry.pred_remaining = max(
+                                r.entry.pred_remaining - 1.0, 0.0)
+                    emitted[r.rid] = n
         return emitted
 
 

@@ -12,6 +12,8 @@ from repro.config import get_config
 from repro.metrics import (EventLog, StreamingQuantiles, check_invariants,
                            report_json, report_markdown, rollup)
 from repro.metrics.events import Event
+from repro.metrics.spans import PhaseRecorder
+from repro.serving import engine as engine_mod
 from repro.serving.costmodel import CostModel, HardwareSpec
 from repro.serving.engine import run_policy
 from repro.serving.workload import WorkloadConfig, generate
@@ -155,9 +157,10 @@ def test_event_log_merge_orders_by_time():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("policy", ["trail", "fcfs", "srpt"])
-def test_metrics_layer_does_not_perturb_results(policy):
-    """Acceptance pin: enabling the event log leaves every scheduling
-    result byte-identical — latencies, TTFTs, preemption counts."""
+def test_metrics_layer_does_not_perturb_results(policy, monkeypatch):
+    """Acceptance pin: enabling the event log, or timing the engine's host
+    phases, leaves every scheduling result byte-identical — latencies,
+    TTFTs, preemption counts."""
     reqs = _small_workload()
     log = EventLog()
     s_with = run_policy(CFG, policy, reqs, hardware=HW, event_log=log,
@@ -168,6 +171,14 @@ def test_metrics_layer_does_not_perturb_results(policy):
     assert s_with.ttfts == s_without.ttfts
     assert s_with.n_preemptions == s_without.n_preemptions
     assert len(log) > 0
+    # sim engines share the no-op recorder; a live one in its place
+    rec = PhaseRecorder()
+    monkeypatch.setattr(engine_mod, "NO_PHASES", rec)
+    s_timed = run_policy(CFG, policy, reqs, hardware=HW, mem_budget=1 << 26)
+    assert s_timed.summary() == s_without.summary()
+    assert s_timed.latencies == s_without.latencies
+    assert s_timed.ttfts == s_without.ttfts
+    assert rec.snapshot()["engine.step"]["calls"] >= s_timed.iterations > 0
 
 
 def test_engine_rollup_matches_engine_stats():
