@@ -237,13 +237,15 @@ def dequantize(q, scale):
     return q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
 
 
-def cache_write(cache_l, k_new, v_new, positions, window: int):
-    """Write S new entries per sequence into one layer's cache.
+def cache_write(cache, layer, k_new, v_new, positions, window: int):
+    """Write S new entries per sequence into one layer of a stacked cache.
 
-    cache_l: {"k": (B,slots,KH,hd), ...}; k_new: (B,S,KH,hd);
-    positions: (B,S) absolute positions. Returns updated cache_l.
+    cache: {"k": (L,B,slots,KH,hd), ...}; layer: scalar index into L;
+    k_new: (B,S,KH,hd); positions: (B,S) absolute positions. Returns the
+    updated stacked cache: only the written entries change, so a cache
+    carried through the layer scan is updated in place.
     """
-    slots = cache_l["k"].shape[1]
+    slots = cache["k"].shape[2]
     B = k_new.shape[0]
     slot_idx = positions % slots if window else positions
     # invalid (-1) or overflowing positions -> index `slots` (out of bounds),
@@ -253,32 +255,33 @@ def cache_write(cache_l, k_new, v_new, positions, window: int):
         ok &= positions < slots
     slot_idx = jnp.where(ok, slot_idx, slots)
     b_idx = jnp.arange(B)[:, None]
-    out = dict(cache_l)
-    if "k_scale" in cache_l:
+    out = dict(cache)
+    if "k_scale" in cache:
         k_q, k_s = _quantize(k_new)
         v_q, v_s = _quantize(v_new)
-        out["k"] = cache_l["k"].at[b_idx, slot_idx].set(k_q, mode="drop")
-        out["v"] = cache_l["v"].at[b_idx, slot_idx].set(v_q, mode="drop")
-        out["k_scale"] = cache_l["k_scale"].at[b_idx, slot_idx].set(
+        out["k"] = cache["k"].at[layer, b_idx, slot_idx].set(k_q, mode="drop")
+        out["v"] = cache["v"].at[layer, b_idx, slot_idx].set(v_q, mode="drop")
+        out["k_scale"] = cache["k_scale"].at[layer, b_idx, slot_idx].set(
             k_s, mode="drop")
-        out["v_scale"] = cache_l["v_scale"].at[b_idx, slot_idx].set(
+        out["v_scale"] = cache["v_scale"].at[layer, b_idx, slot_idx].set(
             v_s, mode="drop")
     else:
-        out["k"] = cache_l["k"].at[b_idx, slot_idx].set(
-            k_new.astype(cache_l["k"].dtype), mode="drop")
-        out["v"] = cache_l["v"].at[b_idx, slot_idx].set(
-            v_new.astype(cache_l["v"].dtype), mode="drop")
-    out["kpos"] = cache_l["kpos"].at[b_idx, slot_idx].set(
+        out["k"] = cache["k"].at[layer, b_idx, slot_idx].set(
+            k_new.astype(cache["k"].dtype), mode="drop")
+        out["v"] = cache["v"].at[layer, b_idx, slot_idx].set(
+            v_new.astype(cache["v"].dtype), mode="drop")
+    out["kpos"] = cache["kpos"].at[layer, b_idx, slot_idx].set(
         positions, mode="drop")
     return out
 
 
-def self_attention_cached(cfg: ModelConfig, p, h, cache_l, q_pos, *,
+def self_attention_cached(cfg: ModelConfig, p, h, cache, layer, q_pos, *,
                           window: int = 0):
     """One layer of cached self-attention on a token chunk.
 
-    h: (B,S,d); cache_l holds this layer's slots; q_pos: (B,S) absolute
-    positions of the chunk tokens. Returns (out (B,S,d), new cache_l).
+    h: (B,S,d); cache holds the run's stacked slots (L,B,slots,...) and
+    ``layer`` picks this layer's; q_pos: (B,S) absolute positions of the
+    chunk tokens. Returns (out (B,S,d), new stacked cache).
     """
     q, k, v = project_qkv(cfg, p, h)
     if cfg.use_rope:
@@ -291,26 +294,27 @@ def self_attention_cached(cfg: ModelConfig, p, h, cache_l, q_pos, *,
         # path below copies the whole cache every step (qwen decode_32k:
         # +50 GB/dev of transients). Safe at S=1: a ring slot overwritten by
         # the new token held a position <= q_pos - window, already masked.
-        new_cache = cache_write(cache_l, k, v, q_pos, window)
-        out = attend(cfg, q, new_cache["k"], new_cache["v"], q_pos,
-                     new_cache["kpos"], window=window,
-                     k_scale=new_cache.get("k_scale"),
-                     v_scale=new_cache.get("v_scale"))
+        new_cache = cache_write(cache, layer, k, v, q_pos, window)
+        lay = {key: x[layer] for key, x in new_cache.items()}
+        out = attend(cfg, q, lay["k"], lay["v"], q_pos, lay["kpos"],
+                     window=window, k_scale=lay.get("k_scale"),
+                     v_scale=lay.get("v_scale"))
         return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(h.dtype), new_cache
     # Chunk path: attend over the PRE-write cache plus the fresh in-chunk
     # K/V. Writing first would let ring-buffer slots be clobbered by later
     # in-chunk tokens that earlier queries still need (and would
     # double-count global slots).
-    if "k_scale" in cache_l:     # int8 cache: dequantize the prefix (chunk
-        k_cache = dequantize(cache_l["k"], cache_l["k_scale"]).astype(k.dtype)
-        v_cache = dequantize(cache_l["v"], cache_l["v_scale"]).astype(v.dtype)
+    lay = {key: x[layer] for key, x in cache.items()}
+    if "k_scale" in lay:         # int8 cache: dequantize the prefix (chunk
+        k_cache = dequantize(lay["k"], lay["k_scale"]).astype(k.dtype)
+        v_cache = dequantize(lay["v"], lay["v_scale"]).astype(v.dtype)
     else:
-        k_cache, v_cache = cache_l["k"], cache_l["v"]
+        k_cache, v_cache = lay["k"], lay["v"]
     k_all = jnp.concatenate([k_cache, k], axis=1)
     v_all = jnp.concatenate([v_cache, v], axis=1)
-    kpos_all = jnp.concatenate([cache_l["kpos"], q_pos], axis=1)
+    kpos_all = jnp.concatenate([lay["kpos"], q_pos], axis=1)
     out = attend(cfg, q, k_all, v_all, q_pos, kpos_all, window=window)
-    new_cache = cache_write(cache_l, k, v, q_pos, window)
+    new_cache = cache_write(cache, layer, k, v, q_pos, window)
     return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(h.dtype), new_cache
 
 
@@ -340,55 +344,60 @@ def init_paged_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     }
 
 
-def gather_pages(x_pages, block_table):
-    """x_pages: (P, ps, ...); block_table: (B, pmax) -> (B, pmax*ps, ...).
+def gather_pages(x_pages, layer, block_table):
+    """x_pages: (L,P,ps,...); block_table: (B,pmax) -> (B,pmax*ps,...).
 
-    The gathered view is ordered by logical position (block tables map
-    logical page i of a sequence to entry i), so downstream position
-    masking sees a plain per-sequence cache."""
-    g = x_pages[block_table]
+    One gather of the pages each row maps, in layer ``layer`` of a run's
+    stacked pool: no whole-layer slice in between. The gathered view is
+    ordered by logical position (block tables map logical page i of a
+    sequence to entry i), so downstream position masking sees a plain
+    per-sequence cache."""
+    g = x_pages[layer, block_table]
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
-def paged_cache_write(cache_l, k_new, v_new, positions, block_table):
+def paged_cache_write(cache, layer, k_new, v_new, positions, block_table):
     """Write S new entries per sequence through the block table.
 
-    cache_l: {"pk": (P,ps,KH,hd), "pv": ..., "pkpos": (P,ps)};
-    k_new/v_new: (B,S,KH,hd); positions: (B,S) absolute (-1 = inactive);
-    block_table: (B, pmax). Writes resolving to the null page (0) or past
-    the table are dropped, like the contiguous path's mode="drop"."""
-    P, ps, KH, hd = cache_l["pk"].shape
+    cache: {"pk": (L,P,ps,KH,hd), "pv": ..., "pkpos": (L,P,ps)}, a run's
+    stacked pool, written in layer ``layer``; k_new/v_new: (B,S,KH,hd);
+    positions: (B,S) absolute (-1 = inactive); block_table: (B, pmax).
+    Writes resolving to the null page (0) or past the table are dropped,
+    like the contiguous path's mode="drop"."""
+    L, P, ps, KH, hd = cache["pk"].shape
     pmax = block_table.shape[1]
     pidx = positions // ps
     page_ids = jnp.take_along_axis(
         block_table, jnp.clip(pidx, 0, pmax - 1), axis=1)
     ok = (positions >= 0) & (pidx < pmax) & (page_ids > 0)
     flat = jnp.where(ok, page_ids * ps + positions % ps, P * ps)
-    out = dict(cache_l)
-    out["pk"] = cache_l["pk"].reshape(P * ps, KH, hd).at[flat].set(
-        k_new.astype(cache_l["pk"].dtype), mode="drop").reshape(P, ps, KH, hd)
-    out["pv"] = cache_l["pv"].reshape(P * ps, KH, hd).at[flat].set(
-        v_new.astype(cache_l["pv"].dtype), mode="drop").reshape(P, ps, KH, hd)
-    out["pkpos"] = cache_l["pkpos"].reshape(P * ps).at[flat].set(
-        positions, mode="drop").reshape(P, ps)
+    out = dict(cache)
+    for key, new in (("pk", k_new), ("pv", v_new)):
+        pool = cache[key]
+        out[key] = pool.reshape(L, P * ps, KH, hd).at[layer, flat].set(
+            new.astype(pool.dtype), mode="drop").reshape(pool.shape)
+    out["pkpos"] = cache["pkpos"].reshape(L, P * ps).at[layer, flat].set(
+        positions, mode="drop").reshape(L, P, ps)
     return out
 
 
-def self_attention_paged(cfg: ModelConfig, p, h, cache_l, q_pos, block_table,
-                         use_kernels: bool = False):
+def self_attention_paged(cfg: ModelConfig, p, h, cache, layer, q_pos,
+                         block_table, use_kernels: bool = False):
     """One layer of paged cached self-attention (global attention only).
 
     Same semantics as ``self_attention_cached`` with window=0, but K/V are
-    read and written through the block table. Decode (S=1) writes first
-    and attends over the updated pool (pages are request-exclusive, so no
-    in-chunk clobber hazard exists); prefill chunks attend over the
-    gathered prefix plus the fresh in-chunk K/V, then write.
+    read and written through the block table in layer ``layer`` of the
+    run's stacked pool. Decode (S=1) writes first and attends over the
+    updated pool (pages are request-exclusive, so no in-chunk clobber
+    hazard exists); prefill chunks attend over the gathered prefix plus
+    the fresh in-chunk K/V, then write.
 
     With ``use_kernels`` the gather+attend reference is replaced by the
     Pallas flash-decode kernels, which stream pages HBM->VMEM through the
     scalar-prefetched block table instead of materializing the gathered
     cache: single-query for decode, multi-query (write-first, one page
-    stream for all S chunk tokens) for prefill chunks.
+    stream for all S chunk tokens) for prefill chunks. The kernels read
+    one layer's pool, sliced out of the stack.
     """
     q, k, v = project_qkv(cfg, p, h)
     if cfg.use_rope:
@@ -397,31 +406,31 @@ def self_attention_paged(cfg: ModelConfig, p, h, cache_l, q_pos, block_table,
     B, S = h.shape[:2]
     if use_kernels:
         from repro.kernels import ops
-        new_cache = paged_cache_write(cache_l, k, v, q_pos, block_table)
+        new_cache = paged_cache_write(cache, layer, k, v, q_pos, block_table)
+        pk, pv, pkpos = (new_cache[key][layer] for key in ("pk", "pv", "pkpos"))
         if S == 1:
             out = ops.paged_decode_attention(
-                q[:, 0], new_cache["pk"], new_cache["pv"],
-                new_cache["pkpos"], block_table, q_pos[:, 0],
+                q[:, 0], pk, pv, pkpos, block_table, q_pos[:, 0],
                 softcap=cfg.attn_logit_softcap)[:, None]
         else:
             out = ops.paged_decode_attention_multi(
-                q, new_cache["pk"], new_cache["pv"], new_cache["pkpos"],
-                block_table, q_pos, softcap=cfg.attn_logit_softcap)
+                q, pk, pv, pkpos, block_table, q_pos,
+                softcap=cfg.attn_logit_softcap)
         return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(h.dtype), new_cache
     if S == 1:
-        new_cache = paged_cache_write(cache_l, k, v, q_pos, block_table)
-        out = attend(cfg, q, gather_pages(new_cache["pk"], block_table),
-                     gather_pages(new_cache["pv"], block_table), q_pos,
-                     gather_pages(new_cache["pkpos"], block_table))
+        new_cache = paged_cache_write(cache, layer, k, v, q_pos, block_table)
+        out = attend(cfg, q, gather_pages(new_cache["pk"], layer, block_table),
+                     gather_pages(new_cache["pv"], layer, block_table), q_pos,
+                     gather_pages(new_cache["pkpos"], layer, block_table))
         return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(h.dtype), new_cache
-    k_all = jnp.concatenate([gather_pages(cache_l["pk"], block_table), k],
-                            axis=1)
-    v_all = jnp.concatenate([gather_pages(cache_l["pv"], block_table), v],
-                            axis=1)
+    k_all = jnp.concatenate(
+        [gather_pages(cache["pk"], layer, block_table), k], axis=1)
+    v_all = jnp.concatenate(
+        [gather_pages(cache["pv"], layer, block_table), v], axis=1)
     kpos_all = jnp.concatenate(
-        [gather_pages(cache_l["pkpos"], block_table), q_pos], axis=1)
+        [gather_pages(cache["pkpos"], layer, block_table), q_pos], axis=1)
     out = attend(cfg, q, k_all, v_all, q_pos, kpos_all)
-    new_cache = paged_cache_write(cache_l, k, v, q_pos, block_table)
+    new_cache = paged_cache_write(cache, layer, k, v, q_pos, block_table)
     return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(h.dtype), new_cache
 
 

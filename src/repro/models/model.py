@@ -253,6 +253,10 @@ class Model:
     # Cached forward (chunked prefill; decode is the S=1 case)
     # ------------------------------------------------------------------
     def _cached_trunk(self, params, cache, h, q_pos, decode: bool):
+        """Run every layer over ``h``. Each run's stacked cache rides in its
+        layer scan's carry beside ``h``; the scan hands each layer its index,
+        so a layer reads its slice of the stack and writes only the entries
+        the step changes, and XLA updates the donated cache in place."""
         cfg = self.cfg
         new_cache = dict(cache)
         tap = None
@@ -260,19 +264,21 @@ class Model:
         block_table = cache.get("block_table")     # shared across all layers
         for ri, (kinds, nb) in enumerate(self.runs):
             def body(carry, xs, _kinds=kinds):
-                p_blk, c_blk = xs
+                h, run_cache = carry
+                p_blk, layer = xs
                 new_blk = []
                 aux = jnp.float32(0)
                 for j, kind in enumerate(_kinds):
-                    carry, c_new, a = tfm.block_cached(
-                        cfg, kind, p_blk[j], carry, c_blk[j], q_pos,
+                    h, c_new, a = tfm.block_cached(
+                        cfg, kind, p_blk[j], h, run_cache[j], layer, q_pos,
                         decode=decode, block_table=block_table,
                         use_kernels=self.use_kernels)
                     new_blk.append(c_new)
                     aux = aux + a
-                return carry, (tuple(new_blk), aux)
-            h, (run_cache, auxs) = jax.lax.scan(
-                body, h, (params["layers"][ri], cache[f"run_{ri}"]))
+                return (h, tuple(new_blk)), aux
+            (h, run_cache), auxs = jax.lax.scan(
+                body, (h, cache[f"run_{ri}"]),
+                (params["layers"][ri], jnp.arange(nb, dtype=jnp.int32)))
             new_cache[f"run_{ri}"] = run_cache
             aux_total = aux_total + jnp.sum(auxs)
             if ri == self.tap_run:

@@ -151,58 +151,63 @@ def block_train(cfg: ModelConfig, kind: str, p, h, enc_out=None, positions=None)
     return h + y, aux
 
 
-def block_cached(cfg: ModelConfig, kind: str, p, h, cache_l, q_pos,
+def _ssm_cached(cfg: ModelConfig, p, hn, cache, layer, decode: bool):
+    """SSM part of a cached block on layer ``layer`` of the run's stacked
+    state. Returns (y, {"ssm_state", "conv_buf"} with that layer updated)."""
+    state, conv_buf = cache["ssm_state"][layer], cache["conv_buf"][layer]
+    if decode:
+        y, (st, cb) = ssm_mod.ssm_decode_step(cfg, p, hn, state, conv_buf)
+    else:
+        y, (st, cb) = ssm_mod.ssm_forward(cfg, p, hn, state=state,
+                                          conv_buf=conv_buf)
+    return y, {"ssm_state": cache["ssm_state"].at[layer].set(st),
+               "conv_buf": cache["conv_buf"].at[layer].set(cb)}
+
+
+def block_cached(cfg: ModelConfig, kind: str, p, h, cache, layer, q_pos,
                  decode: bool = False, block_table=None,
                  use_kernels: bool = False):
-    """Cached-path block (prefill chunk or decode). Returns (h, cache_l, aux).
+    """Cached-path block (prefill chunk or decode). Returns (h, cache, aux).
 
     h: (B,S,d); q_pos: (B,S) absolute positions (-1 = inactive slot).
+    ``cache`` is the run's stacked cache (every leaf has a leading layer
+    axis) and ``layer`` this block's index into it: reads take that layer,
+    writes touch only what the step changes, so the run scan carries the
+    whole cache and updates it in place.
     ``block_table`` (B, pmax) routes K/V through the shared page pool when
     this run's cache is paged (pk/pv/pkpos leaves). ``use_kernels`` swaps
     the paged gather+attend reference for the Pallas flash-decode kernels
     (single-query for decode, multi-query for prefill chunks).
     """
     hn = apply_norm(cfg, p["norm1"], h)
-    new_cache = dict(cache_l)
+    new_cache = dict(cache)
     if kind == KIND_SSM:
-        if decode:
-            y, (st, cb) = ssm_mod.ssm_decode_step(
-                cfg, p["ssm"], hn, cache_l["ssm_state"], cache_l["conv_buf"])
-        else:
-            y, (st, cb) = ssm_mod.ssm_forward(
-                cfg, p["ssm"], hn, state=cache_l["ssm_state"],
-                conv_buf=cache_l["conv_buf"])
-        new_cache["ssm_state"], new_cache["conv_buf"] = st, cb
+        y, st = _ssm_cached(cfg, p["ssm"], hn, cache, layer, decode)
+        new_cache.update(st)
         return h + y, new_cache, jnp.float32(0)
 
     window = _kind_window(cfg, kind)
     with jax.named_scope("attention"):
-        if "pk" in cache_l:
-            kvcache = {k: cache_l[k] for k in ("pk", "pv", "pkpos")}
+        if "pk" in cache:
+            kvcache = {k: cache[k] for k in ("pk", "pv", "pkpos")}
             a, kv_new = attn.self_attention_paged(cfg, p["attn"], hn, kvcache,
-                                                  q_pos, block_table,
+                                                  layer, q_pos, block_table,
                                                   use_kernels=use_kernels)
         else:
             kv_keys = ("k", "v", "kpos", "k_scale", "v_scale")
-            kvcache = {k: cache_l[k] for k in kv_keys if k in cache_l}
+            kvcache = {k: cache[k] for k in kv_keys if k in cache}
             a, kv_new = attn.self_attention_cached(cfg, p["attn"], hn, kvcache,
-                                                   q_pos, window=window)
+                                                   layer, q_pos, window=window)
     new_cache.update(kv_new)
     if kind == KIND_HYBRID:
-        if decode:
-            s, (st, cb) = ssm_mod.ssm_decode_step(
-                cfg, p["ssm"], hn, cache_l["ssm_state"], cache_l["conv_buf"])
-        else:
-            s, (st, cb) = ssm_mod.ssm_forward(
-                cfg, p["ssm"], hn, state=cache_l["ssm_state"],
-                conv_buf=cache_l["conv_buf"])
-        new_cache["ssm_state"], new_cache["conv_buf"] = st, cb
+        s, st = _ssm_cached(cfg, p["ssm"], hn, cache, layer, decode)
+        new_cache.update(st)
         a = 0.5 * (a + s)
     h = h + a
-    if "cross" in p and "ck" in cache_l:
+    if "cross" in p and "ck" in cache:
         h = h + attn.cross_attention(cfg, p["cross"],
                                      apply_norm(cfg, p["norm_cross"], h),
-                                     cache_l["ck"], cache_l["cv"])
+                                     cache["ck"][layer], cache["cv"][layer])
     with jax.named_scope("mlp"):
         y, aux = _mlp_part(cfg, kind, p, h)
     return h + y, new_cache, aux

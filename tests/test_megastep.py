@@ -8,12 +8,15 @@ per-row budget/EOS halting, and SSM ``_mask_recurrent`` state. Donation
 across preemption resets.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.config import get_smoke_config
+from repro.models import transformer as tfm
 from repro.models.model import Model
 from repro.serving.engine import run_policy
 from repro.serving.kv_cache import PagedSlotPool, SlotPool, donating_jit
@@ -247,3 +250,104 @@ def test_model_paged_kernels_parity():
     assert float(np.max(np.abs(outs[0][0] - outs[1][0]))) < 2e-4
     assert np.array_equal(outs[0][1], outs[1][1])
     assert float(np.max(np.abs(outs[0][2] - outs[1][2]))) < 2e-4
+
+
+class _LayerByLayer(Model):
+    """The cached trunk with each layer on its own slice of the run's
+    cache: the layer scan takes the stacked cache as ``xs``, hands each
+    layer a one-layer slice, and stacks the slices back as ``ys``.
+
+    A scan, not an unrolled loop: the hidden state is rounded to the
+    compute dtype between layers exactly as in the model's scan, so the
+    comparison can be bit for bit."""
+
+    def _cached_trunk(self, params, cache, h, q_pos, decode):
+        new_cache = dict(cache)
+        tap = None
+        for ri, (kinds, nb) in enumerate(self.runs):
+            def body(h, xs, _kinds=kinds):
+                p_blk, c_blk = xs
+                new_blk = []
+                for j, kind in enumerate(_kinds):
+                    one = jax.tree.map(lambda x: x[None], c_blk[j])
+                    h, one, _ = tfm.block_cached(
+                        self.cfg, kind, p_blk[j], h, one, 0, q_pos,
+                        decode=decode, block_table=cache.get("block_table"))
+                    new_blk.append(jax.tree.map(lambda x: x[0], one))
+                return h, tuple(new_blk)
+            h, new_cache[f"run_{ri}"] = jax.lax.scan(
+                body, h, (params["layers"][ri], cache[f"run_{ri}"]))
+            if ri == self.tap_run:
+                tap = h
+        return h, new_cache, tap, jnp.float32(0)
+
+
+def _six_layers(arch: str, **changes):
+    """The arch's smoke layer pattern repeated to six layers, probe tapped
+    at layer 3: runs of several layers (alternating kinds scan as
+    multi-layer blocks), so the scan hands out layer indices past 0."""
+    cfg = get_smoke_config(arch)
+    kinds = (cfg.layer_kinds * 6)[:6]
+    return dataclasses.replace(
+        cfg, num_layers=6, layer_kinds=kinds,
+        probe=dataclasses.replace(cfg.probe, tap_layer=3), **changes)
+
+
+_KINDS = {                                  # each layer kind's smoke config
+    "dense": ("trail-llama", {}),
+    "local": ("gemma2-9b", {}),             # ring buffer + global layers
+    "moe": ("olmoe-1b-7b", {}),
+    "ssm": ("mamba2-370m", {}),
+    "hybrid": ("hymba-1.5b", {}),           # windowed attention + SSM
+    "cross": ("whisper-tiny", {}),          # cross-attention cache
+    "int8": ("trail-llama", {"kv_quant": True}),   # K/V with scales
+}
+_CASES = [(kind, layout) for kind in _KINDS for layout in ("contig", "paged")
+          if not (kind == "int8" and layout == "paged")]   # no paged int8
+
+
+@pytest.mark.real
+@pytest.mark.parametrize("kind,kv_layout", _CASES,
+                         ids=[f"{k}-{lay}" for k, lay in _CASES])
+def test_scan_carried_cache_matches_layer_by_layer(kind, kv_layout):
+    """prefill_chunk then decode_multi with each run's cache carried
+    through the layer scan give the same tokens, probe posteriors and
+    cache leaves, bit for bit, as the layers run one at a time on
+    per-layer slices."""
+    arch, changes = _KINDS[kind]
+    cfg = _six_layers(arch, **changes)
+    B, max_len, ps, k = 3, 32, 8, 3
+    m = Model(cfg)
+    params = m.init(jax.random.key(0))
+    prompts = jax.random.randint(jax.random.key(1), (B, 12), 4,
+                                 cfg.vocab_size)
+    valid = jnp.arange(12)[None, :] < jnp.array([[12], [7], [0]])
+    extra = {}
+    if cfg.cross_attention:
+        extra["enc_embeds"] = jax.random.normal(
+            jax.random.key(2), (B, cfg.encoder_seq, cfg.d_model))
+    active = jnp.array([True, True, False])
+    budget = jnp.array([k, 2, k], jnp.int32)
+
+    outs = []
+    for model in (m, _LayerByLayer(cfg)):
+        cache = model.init_cache(B, max_len, kv_layout=kv_layout,
+                                 page_size=ps)
+        if kv_layout == "paged":      # scrambled, disjoint pages per row
+            cache["block_table"] = jnp.asarray(
+                [[3, 9, 0, 0], [7, 1, 0, 0], [0, 0, 0, 0]], jnp.int32)
+        logits, cache, tap_sum, _ = jax.jit(model.prefill_chunk)(
+            params, cache, prompts, valid, **extra)
+        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        toks, cache, probs, _ = jax.jit(
+            model.decode_multi, static_argnames=("k",))(
+                params, cache, tok, active, budget, k=k)
+        outs.append(jax.tree.map(np.asarray,
+                                 (logits, tap_sum, toks, probs, cache)))
+    got, ref = outs
+    assert (got[2] >= 0).any()                      # some row decoded
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(ref)):
+        assert a.dtype == b.dtype and np.array_equal(a, b), \
+            jax.tree_util.keystr(path)

@@ -61,27 +61,29 @@ def test_softcap():
 
 
 def test_cache_write_drop_semantics():
-    cache = A.init_kv_cache(CFG, batch=2, max_len=8, n_layers=1)
-    layer = jax.tree.map(lambda x: x[0], cache)
+    cache = A.init_kv_cache(CFG, batch=2, max_len=8, n_layers=2)
     k_new = jnp.ones((2, 3, CFG.num_kv_heads, CFG.head_dim))
     pos = jnp.asarray([[0, 1, 2], [-1, 5, 99]])   # -1 and overflow dropped
-    out = A.cache_write(layer, k_new, k_new, pos, window=0)
-    assert bool(jnp.all(out["kpos"][0, :3] == jnp.asarray([0, 1, 2])))
-    assert int(out["kpos"][1, 5]) == 5
-    assert int(out["kpos"][1, 0]) == -1           # -1 write dropped
-    assert bool(jnp.all(out["k"][1, 0] == 0))
+    out = A.cache_write(cache, 1, k_new, k_new, pos, window=0)
+    layer = jax.tree.map(lambda x: x[1], out)
+    assert bool(jnp.all(layer["kpos"][0, :3] == jnp.asarray([0, 1, 2])))
+    assert int(layer["kpos"][1, 5]) == 5
+    assert int(layer["kpos"][1, 0]) == -1         # -1 write dropped
+    assert bool(jnp.all(layer["k"][1, 0] == 0))
+    # the other layer of the stack is untouched
+    assert bool(jnp.all(out["kpos"][0] == -1))
+    assert bool(jnp.all(out["k"][0] == 0))
 
 
 def test_ring_buffer_wraparound():
     win = 4
     cache = A.init_kv_cache(CFG, batch=1, max_len=16, n_layers=1, window=win)
-    layer = jax.tree.map(lambda x: x[0], cache)
     k_new = jnp.arange(6, dtype=jnp.float32)[None, :, None, None] * jnp.ones(
         (1, 6, CFG.num_kv_heads, CFG.head_dim))
     pos = jnp.arange(6)[None]
-    out = A.cache_write(layer, k_new, k_new, pos, window=win)
+    out = A.cache_write(cache, 0, k_new, k_new, pos, window=win)
     # slots hold positions 4,5,2,3 (ring of width 4)
-    assert sorted(np.asarray(out["kpos"][0]).tolist()) == [2, 3, 4, 5]
+    assert sorted(np.asarray(out["kpos"][0, 0]).tolist()) == [2, 3, 4, 5]
 
 
 def test_ssd_chunk_invariance():
